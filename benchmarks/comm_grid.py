@@ -117,8 +117,11 @@ def _mesh8_child() -> None:
 
 def _mesh8_entries() -> list:
     """Sharded-codec frontier rows, measured in a child process with 8
-    forced host CPU devices (works on any host, incl. 1-device CI)."""
+    forced host CPU devices (works on any host, incl. 1-device CI).
+    The child is pinned to the CPU: this process may already hold the
+    accelerator, and the rows are byte counts, not timings."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
     proc = subprocess.run(

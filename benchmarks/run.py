@@ -63,6 +63,8 @@ def smoke(out_path: str) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         out = "bench_smoke.json"
         if "--out" in sys.argv:
@@ -88,19 +90,23 @@ def main() -> None:
     ]
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for name, mod in modules:
         if only and name not in only:
             continue
         try:
             mod.main()
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
             print(f"{name}_ERROR,0,{e!r}")
+            failed.append(name)
     # roofline table (if dry-run artifacts exist)
     if os.path.isdir("experiments/dryrun") and (not only
                                                 or "roofline" in only):
         from benchmarks import roofline
         roofline.main()
     print(f"total,{(time.time() - t0) * 1e6:.0f},all_benchmarks")
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -96,7 +96,6 @@ from repro.core.strategies import (ControlCtx, CorrCtx, algorithm_spec,
 from repro.data.batching import stack_device_batches
 from repro.kernels.flatpack import (LANES, flat_spec, pack,
                                     pack_broadcast, pack_stacked, unpack)
-from repro.launch.mesh import shard_map_compat
 
 #: Safety factor on the event budget: a run may process at most
 #: ``HORIZON_FACTOR * num_rounds * max(K, M)`` arrivals before the
@@ -223,9 +222,9 @@ class BufferedDriver(object):
             in_specs = (rep, dev, rep, dev, dev)
             if self._has_work:
                 in_specs += (dev,)
-            self._jsolve = jax.jit(shard_map_compat(
-                self._solver, self.mesh, in_specs=in_specs,
-                out_specs=dev, manual_axes=manual))
+            self._jsolve = jax.jit(jax.shard_map(
+                self._solver, mesh=self.mesh, in_specs=in_specs,
+                out_specs=dev, axis_names=set(manual), check_vma=False))
         else:
             self._jsolve = jax.jit(self._solver)
         self._grads = jax.jit(make_batched_grad_fn(loss_fn))
@@ -280,9 +279,10 @@ class BufferedDriver(object):
             in_specs = (rep, rep, dev, dev)
             if self._commit_takes_key:
                 in_specs += (rep, rep)
-            commit = shard_map_compat(
-                commit, mesh, in_specs=in_specs, out_specs=(rep, rep),
-                manual_axes=sharding.axis_name_tuple(axis))
+            commit = jax.shard_map(
+                commit, mesh=mesh, in_specs=in_specs, out_specs=(rep, rep),
+                axis_names=set(sharding.axis_name_tuple(axis)),
+                check_vma=False)
         return jax.jit(commit)
 
     # -- sampling / environment -------------------------------------------

@@ -171,7 +171,6 @@ from repro.data.shard_source import resolve_streaming
 from repro.kernels.codec import codec_aggregate, codec_aggregate_partial
 from repro.kernels.flatpack import (LANES, flat_spec, pack_broadcast,
                                     pack_stacked, unpack)
-from repro.launch.mesh import shard_map_compat
 
 #: Sentinel for "derive the mesh from ``cfg.mesh_devices``" (the
 #: default) vs. an explicit ``mesh=None`` / ``mesh=Mesh`` override.
@@ -544,9 +543,9 @@ class RoundEngine:
                            None, None, None))
             if not with_env:
                 in_specs, env = in_specs[:6], ()
-            f = shard_map_compat(
-                body, mesh, in_specs=in_specs, out_specs=out_specs,
-                manual_axes=manual)
+            f = jax.shard_map(
+                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                axis_names=set(manual), check_vma=False)
             return f(w0, aux, phase_a, batches, valid,
                      jnp.asarray(decay, jnp.float32), *env)
 
@@ -573,16 +572,19 @@ def _pad_cohort(stacked, valid, nb: int):
     return stacked, valid
 
 
-def _make_stacked_eval(loss_fn: Callable, eval_batches, eval_valid,
-                       eval_weights) -> Callable:
+def _make_stacked_eval(loss_fn: Callable) -> Callable:
     """On-device global loss over the all-device stacked eval tensors.
 
     Mirrors ``FederatedTrainer.global_loss`` exactly: per device the mean
     batch loss over its *valid* (own) batches, then the p_k-weighted mean
     over devices — but as one traced expression usable inside the scanned
-    driver's ``lax.cond``."""
+    driver's ``lax.cond``.  ``eval_loss(p, eval_data)`` takes the
+    ``(batches, valid, weights)`` stacks as an argument: closed over,
+    they would be baked into the program as constants."""
 
-    def eval_loss(p):
+    def eval_loss(p, eval_data):
+        eval_batches, eval_valid, eval_weights = eval_data
+
         def per_device(b, v):
             def accum(acc, xs):
                 batch, vi = xs
@@ -691,7 +693,15 @@ class ScannedDriver:
                                                         self.mesh)
             eb = sharding.shard_stacked(eb, self.mesh)
             ev = sharding.shard_stacked(ev, self.mesh)
-        self._eval_loss = _make_stacked_eval(loss_fn, eb, ev, ew)
+        self._eval_loss = _make_stacked_eval(loss_fn)
+        #: the chunk programs' data arguments: the eval stacks, plus the
+        #: all-client train stacks on the stacked plan.  Arguments, not
+        #: closures — closed-over arrays would be compiled in as
+        #: constants (gigabytes at FEMNIST's N=200).
+        self._data = {"eval": (eb, ev, ew)}
+        if not self.streaming:
+            self._data.update(batches=self.batches_all,
+                              valid=self.valid_all)
         # streaming sources publish weights=None (uniform sampling with
         # no O(N) weight vector); dense datasets keep their size-
         # proportional marginals
@@ -720,9 +730,10 @@ class ScannedDriver:
     # -- scan program -----------------------------------------------------
 
     def _make_chunk(self, inject: bool) -> Callable:
-        """Build ``chunk(carry, xs) -> (carry, losses)``: a lax.scan whose
-        body is one whole federated round — the engine's generic
-        ``round_body`` plus on-device selection gather/scatter.
+        """Build ``chunk(carry, xs, data) -> (carry, losses)``: a
+        lax.scan whose body is one whole federated round — the engine's
+        generic ``round_body`` plus on-device selection gather/scatter
+        from the all-client stacks in ``data`` (``self._data``).
         ``inject=True`` reads each round's selection from ``xs["sel"]``
         (tests / A-B comparisons); ``inject=False`` samples on device
         from the carried PRNG key."""
@@ -735,7 +746,6 @@ class ScannedDriver:
                       else self.engine.round_body_env)
         n = self.num_devices
         k_sel = self.k_sel
-        batches_all, valid_all = self.batches_all, self.valid_all
         probs = self.probs
         has_controls = "controls" in self._state_fields
         aux_fields = tuple(f for f in self._state_fields
@@ -747,10 +757,12 @@ class ScannedDriver:
                 key, n, k_sel, p=probs,
                 replace=cfg.sample_with_replacement)
 
-        def gather(sel):
-            return tmap(lambda x: x[sel], batches_all), valid_all[sel]
+        def body(data, carry, xs):
+            batches_all, valid_all = data["batches"], data["valid"]
 
-        def body(carry, xs):
+            def gather(sel):
+                return tmap(lambda x: x[sel], batches_all), valid_all[sel]
+
             new = dict(carry)
             if inject:
                 s1, s2 = xs["sel"][0], xs["sel"][1]
@@ -845,7 +857,8 @@ class ScannedDriver:
                                  aux_new["ef"]))
             new["params"] = params
             loss = jax.lax.cond(
-                xs["do_eval"], self._eval_loss,
+                xs["do_eval"],
+                lambda p: self._eval_loss(p, data["eval"]),
                 lambda p: jnp.float32(jnp.nan), params)
             if trivial:
                 return new, loss
@@ -853,15 +866,15 @@ class ScannedDriver:
                          "effective_k": stats["effective_k"],
                          "effective_a": stats["effective_a"]}
 
-        def chunk(carry, xs):
-            return jax.lax.scan(body, carry, xs)
+        def chunk(carry, xs, data):
+            return jax.lax.scan(lambda c, x: body(data, c, x), carry, xs)
 
         return chunk
 
     # -- streaming program (population-scale sources) ---------------------
 
     def _make_stream_chunk(self) -> Callable:
-        """Build the streaming ``chunk(carry, xs) -> (carry, ys)``.
+        """Build the streaming ``chunk(carry, xs, data) -> (carry, ys)``.
 
         Same generic round-body interpretation as ``_make_chunk``, but
         every per-cohort input — batch stacks, per-client state rows,
@@ -883,7 +896,7 @@ class ScannedDriver:
         aux_fields = tuple(f for f in self._state_fields
                            if f != "controls")
 
-        def body(carry, xs):
+        def body(data, carry, xs):
             new = dict(carry)
             decay = (spec.decay(cfg, xs["t"].astype(jnp.float32))
                      if spec.decay is not None else 1.0)
@@ -914,15 +927,16 @@ class ScannedDriver:
                 ys["ef"] = aux_new["ef"]
             new["params"] = params
             ys["loss"] = jax.lax.cond(
-                xs["do_eval"], self._eval_loss,
+                xs["do_eval"],
+                lambda p: self._eval_loss(p, data["eval"]),
                 lambda p: jnp.float32(jnp.nan), params)
             if not trivial:
                 ys["effective_k"] = stats["effective_k"]
                 ys["effective_a"] = stats["effective_a"]
             return new, ys
 
-        def chunk(carry, xs):
-            return jax.lax.scan(body, carry, xs)
+        def chunk(carry, xs, data):
+            return jax.lax.scan(lambda c, x: body(data, c, x), carry, xs)
 
         return chunk
 
@@ -1070,7 +1084,7 @@ class ScannedDriver:
                 if spec.grad_source == "fresh":
                     xs["active_a"] = jnp.stack(
                         [jnp.asarray(r["active_a"]) for r in rows])
-            carry, ys = self._chunk_stream(carry, xs)
+            carry, ys = self._chunk_stream(carry, xs, self._data)
             ys_h = jax.device_get(ys)
             # scatter updated state rows back, in round order (later
             # rounds of the chunk never touch earlier rounds' clients —
@@ -1205,7 +1219,7 @@ class ScannedDriver:
                   "do_eval": jnp.asarray(eval_mask[off:hi])}
             if sel is not None:
                 xs["sel"] = sel[off:hi]
-            carry, ys = chunk_fn(carry, xs)
+            carry, ys = chunk_fn(carry, xs, self._data)
             # chunk boundary: the only host round-trip
             if self.scn_trivial:
                 losses = np.asarray(jax.device_get(ys))

@@ -31,10 +31,9 @@ mesh:
   control carries) so the chunk program starts from the layout the
   shard-mapped round body wants.
 
-``core/engine.py`` wraps the round body in ``shard_map`` over this mesh
-(via the version-compat helpers in ``launch/mesh.py``) and expresses
-every cross-client reduction — ``mean_k``, masked scenario reductions,
-the server pseudo-gradient step's aggregate — through
+``core/engine.py`` wraps the round body in ``jax.shard_map`` over this
+mesh and expresses every cross-client reduction — ``mean_k``, masked
+scenario reductions, the server pseudo-gradient step's aggregate — through
 :func:`tree_psum` / :func:`tree_pmean`, so the whole round stays ONE
 jitted SPMD program whether the reduction is flat or a tree.
 
@@ -66,7 +65,7 @@ from __future__ import annotations
 from typing import Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 #: Name of the mesh axis carrying the stacked federated clients.
 DEVICE_AXIS = "device"
@@ -117,14 +116,16 @@ def make_device_mesh(num_devices: int, edge_shards: int = 1) -> Mesh:
     ``(EDGE_AXIS, DEVICE_AXIS)`` — the hierarchical aggregation tree.
     """
     if edge_shards <= 1:
-        return jax.make_mesh((num_devices,), (DEVICE_AXIS,))
+        return jax.make_mesh((num_devices,), (DEVICE_AXIS,),
+                             axis_types=(AxisType.Auto,))
     if num_devices % edge_shards != 0:
         raise ValueError(
             f"edge_shards={edge_shards} must divide the resolved "
             f"mesh_devices={num_devices} (each edge aggregates an "
             f"equal leaf-device group)")
     return jax.make_mesh((edge_shards, num_devices // edge_shards),
-                         (EDGE_AXIS, DEVICE_AXIS))
+                         (EDGE_AXIS, DEVICE_AXIS),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def mesh_for(cfg) -> Optional[Mesh]:

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dane_update import row_block
 from repro.kernels.flatpack import LANES
 
 #: Smaller than dane_update's 512: each grid instance holds the block
@@ -55,9 +56,7 @@ def _launch_agg(kernel, vals, scales, mask, block_rows, interpret):
     k, rows, _ = vals.shape
     if block_rows is None:
         block_rows = rows if interpret else DEFAULT_BLOCK_ROWS
-    block_rows = min(block_rows, rows)
-    while rows % block_rows != 0:
-        block_rows -= 1
+    block_rows = row_block(rows, block_rows)
     scales = jnp.asarray(scales, jnp.float32).reshape(k, 1)
     mask = jnp.asarray(mask, jnp.float32).reshape(k, 1)
     kspec = pl.BlockSpec((k, 1), lambda i: (0, 0))
@@ -82,8 +81,8 @@ def codec_aggregate(vals, scales, mask, block_rows: int | None = None,
     scale; 0/1 active mask — inactive clients contribute neither signal
     nor count, so an all-inactive cohort yields the zero aggregate and
     the round stays a no-op).  ``block_rows=None`` picks the backend
-    sweet spot exactly like ``dane_update_flat``: largest divisor of
-    ``rows`` ≤ :data:`DEFAULT_BLOCK_ROWS` on TPU, the whole buffer as
+    sweet spot exactly like ``dane_update_flat``: ``row_block`` of
+    ``rows`` and :data:`DEFAULT_BLOCK_ROWS` on TPU, the whole buffer as
     ONE block in interpret mode.
     """
     return _launch_agg(_agg_kernel, vals, scales, mask, block_rows,

@@ -21,7 +21,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+SUBLANES = 8
 DEFAULT_BLOCK_ROWS = 512
+
+
+def row_block(rows: int, target: int) -> int:
+    """Rows per block when tiling a ``rows``-long second-minor axis.
+
+    Mosaic takes a block whose second-minor dim is a multiple of
+    :data:`SUBLANES` or the whole dim.  So: the whole dim when it is at
+    most ``target``; else the largest multiple of :data:`SUBLANES` not
+    above ``target`` that divides ``rows``; else (``rows`` has no such
+    divisor, which a row count padded to :data:`SUBLANES` always has)
+    the whole dim.
+    """
+    if rows <= target:
+        return rows
+    for bb in range(target - target % SUBLANES, 0, -SUBLANES):
+        if rows % bb == 0:
+            return bb
+    return rows
 
 
 def _kernel(eta_ref, mu_ref, w_ref, g_ref, c_ref, a_ref, out_ref):
@@ -40,9 +59,7 @@ def dane_update_2d(w, grad, g_corr, anchor, eta, mu,
                    interpret: bool = False):
     """Core pallas_call on a (rows, LANES) view."""
     rows = w.shape[0]
-    block_rows = min(block_rows, rows)
-    while rows % block_rows != 0:
-        block_rows //= 2
+    block_rows = row_block(rows, block_rows)
     block = (block_rows, LANES)
     grid = (rows // block_rows,)
     spec = pl.BlockSpec(block, lambda i: (i, 0))
@@ -93,8 +110,8 @@ def dane_update_flat(w, grad, g_corr, anchor, eta, mu, mask,
     the ``(K,)`` per-device step mask, expanded (one cheap XLA repeat)
     to the per-row keep column the kernel tiles with the data.
 
-    ``block_rows=None`` picks the backend's sweet spot: on TPU the
-    largest divisor of the total row count ≤ ``DEFAULT_BLOCK_ROWS``
+    ``block_rows=None`` picks the backend's sweet spot: on TPU
+    :func:`row_block` of the total row count and ``DEFAULT_BLOCK_ROWS``
     (VMEM-bounded tiles); in interpret mode the whole buffer as ONE
     block — the interpreter's cost scales with grid steps × full-array
     traffic, so a single grid step is the fast shape on CPU.
@@ -103,9 +120,7 @@ def dane_update_flat(w, grad, g_corr, anchor, eta, mu, mask,
     k = total_rows // rows_per_dev
     if block_rows is None:
         block_rows = total_rows if interpret else DEFAULT_BLOCK_ROWS
-    block_rows = min(block_rows, total_rows)
-    while total_rows % block_rows != 0:
-        block_rows -= 1
+    block_rows = row_block(total_rows, block_rows)
     nblocks = total_rows // block_rows
     m_rows = jnp.repeat(jnp.asarray(mask, jnp.float32), rows_per_dev) \
         .reshape(total_rows, 1)

@@ -14,7 +14,8 @@ the whole step is small enough to fuse into ONE launch:
 - :func:`local_epoch`: the same step *scanned over the batch axis
   inside the kernel* — grid ``(K, E*nb)`` with the running weights in
   VMEM scratch, so a whole local solve is ONE ``pallas_call`` (the
-  per-step valid/cutoff mask arrives precomputed as an SMEM table).
+  per-step valid/cutoff mask arrives precomputed, one client's row of
+  it in SMEM at a time).
 
 Both recompute the analytic softmax-NLL gradient rather than calling
 ``jax.grad``, so they are *not* bit-identical to the XLA autodiff path —
@@ -37,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dane_update import row_block
 
 #: VMEM budget gate for the fused kernels: per-device operand + scratch
 #: footprint (f32 words) beyond which selection falls back to the flat
@@ -100,14 +103,6 @@ def _step_kernel(eta_ref, mu_ref, mask_ref, x_ref, y_ref, w_ref, b_ref,
         ob_ref[0] = jnp.where(keep, bn, b).astype(ob_ref.dtype)
 
 
-def _row_block(batch: int, block: int) -> int:
-    """Largest divisor of ``batch`` not above ``block``."""
-    bb = min(block, batch)
-    while batch % bb:
-        bb -= 1
-    return bb
-
-
 def linear_logistic_step(w, batch, corr, w0, *, eta, mu, mask,
                          block_b: int = 128, interpret: bool = False):
     """One fused masked SGD step for K stacked logistic regressions.
@@ -121,7 +116,7 @@ def linear_logistic_step(w, batch, corr, w0, *, eta, mu, mask,
     """
     K, d, C = w["w"].shape
     B = batch["x"].shape[1]
-    bb = _row_block(B, block_b)
+    bb = row_block(B, block_b)
     nrb = B // bb
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
     eta2 = jnp.asarray(eta, jnp.float32).reshape(1, 1)
@@ -185,7 +180,7 @@ def _epoch_kernel(eta_ref, mu_ref, m_ref, x_ref, y_ref, cw_ref, cb_ref,
     mu = mu_ref[0, 0]
     w0 = w0_ref[0].astype(jnp.float32)
     b0 = b0_ref[0].astype(jnp.float32)
-    keep = m_ref[k, t] > 0.0
+    keep = m_ref[0, 0, t] > 0.0
     wn = w - eta * (gw + cw_ref[0].astype(jnp.float32) + mu * (w - w0))
     bn = b - eta * (gb + cb_ref[0].astype(jnp.float32) + mu * (b - b0))
     ws_ref[...] = jnp.where(keep, wn, w)
@@ -206,9 +201,11 @@ def local_epoch(w0, corr, batches, *, eta, mu, num_epochs: int,
     ``batches``: ``{"x": (K, nb, B, d), "y": (K, nb, B)}``;
     ``step_mask``: (K, E*nb) per-step keep mask in scan order (epochs
     outer, batches inner) — the valid/cutoff semantics of the generic
-    solver, precomputed closed-form by the caller.  The running weights
-    live in VMEM scratch across the sequential step axis; the batch
-    index is ``t % nb`` via the BlockSpec index map.
+    solver, precomputed closed-form by the caller.  It is tiled into
+    SMEM one ``(1, E*nb)`` client row at a time: the whole table
+    outgrows the chip's 1 MiB of SMEM at K·E·nb ≥ 2^18.  The running
+    weights live in VMEM scratch across the sequential step axis; the
+    batch index is ``t % nb`` via the BlockSpec index map.
     """
     d, C = w0["w"].shape
     K, nb, B = batches["x"].shape[:3]
@@ -223,7 +220,9 @@ def local_epoch(w0, corr, batches, *, eta, mu, num_epochs: int,
         kernel,
         grid=(K, T),
         in_specs=[
-            scalar, scalar, scalar,
+            scalar, scalar,
+            pl.BlockSpec((1, 1, T), lambda k, t: (k, 0, 0),
+                         memory_space=pltpu.SMEM),              # mask row
             pl.BlockSpec((1, 1, B, d), lambda k, t: (k, t % nb, 0, 0)),
             pl.BlockSpec((1, 1, B, 1), lambda k, t: (k, t % nb, 0, 0)),
             pl.BlockSpec((1, d, C), lambda k, t: (k, 0, 0)),    # corr w
@@ -244,7 +243,7 @@ def local_epoch(w0, corr, batches, *, eta, mu, num_epochs: int,
             pltpu.VMEM((1, C), jnp.float32),   # running bias
         ],
         interpret=interpret,
-    )(eta2, mu2, jnp.asarray(step_mask, jnp.float32),
+    )(eta2, mu2, jnp.asarray(step_mask, jnp.float32).reshape(K, 1, T),
       batches["x"].astype(jnp.float32),
       batches["y"].astype(jnp.int32).reshape(K, nb, B, 1),
       corr["w"], corr["b"].reshape(K, 1, C),

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import flatpack
-from repro.kernels.dane_update import (LANES, dane_update_2d,
+from repro.kernels.dane_update import (LANES, SUBLANES, dane_update_2d,
                                        dane_update_flat)
 from repro.kernels.flash_attention import flash_attention_3d
 
@@ -26,10 +26,14 @@ def _on_cpu() -> bool:
 # ---------------------------------------------------------------------------
 
 def _pad_2d(a):
-    """Flatten to (rows, LANES) with zero pad; returns (view, orig_size)."""
+    """Flatten to (rows, LANES) with zero pad; returns (view, orig_size).
+
+    ``rows`` is a multiple of ``SUBLANES``, so the kernel can always tile
+    it in aligned row blocks.
+    """
     flat = a.reshape(-1)
     n = flat.shape[0]
-    rows = -(-n // LANES)
+    rows = -(-n // (LANES * SUBLANES)) * SUBLANES
     pad = rows * LANES - n
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])

@@ -28,7 +28,7 @@ from repro.launch import hloanalysis
 from repro.launch import sharding as sh
 from repro.launch import steps
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh, use_mesh)
+                               make_production_mesh)
 from repro.models import transformer
 from repro.models.param import ParamSpec, param_shardings
 
@@ -125,7 +125,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, algo: str,
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(np.prod(list(mesh.shape.values())))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args = build_lowerable(cfg, shape, mesh, algo=algo, remat=remat)
         lowered = fn.lower(*args)
         compiled = lowered.compile()

@@ -1,18 +1,17 @@
-"""Production mesh definitions + mesh/shard_map version-compat shims.
+"""Production mesh definitions.
 
 Target: TPU v5e-class pods — 16x16 = 256 chips per pod, 2 pods = 512 chips.
 ``make_production_mesh`` is a function (never a module-level constant) so
 importing this module touches no jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
-
-``use_mesh`` / ``shard_map_compat`` paper over the moving JAX API surface
-(``jax.set_mesh`` / ``jax.sharding.use_mesh`` / ``Mesh`` context manager;
-``jax.shard_map(axis_names=...)`` vs ``jax.experimental.shard_map(auto=...)``)
-so launch code and tests run unmodified across the JAX versions we see.
+Both meshes use auto axis types: sharding is propagated by the compiler,
+as the models' ``with_sharding_constraint`` annotations
+(``models/shardutil.py``) expect.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # Hardware constants used by the roofline model (assignment-specified).
 PEAK_FLOPS_BF16 = 197e12      # per chip, FLOP/s
@@ -24,43 +23,10 @@ CHIPS_PER_POD = 256
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs through the same code path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Resolution order: ``jax.set_mesh`` (newest) -> ``jax.sharding.use_mesh``
-    -> the ``Mesh`` object itself (a context manager on older JAX).
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    setter = getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
-
-def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes,
-                     check: bool = False):
-    """``shard_map`` manual over ``manual_axes``, auto over the rest.
-
-    New JAX spells this ``jax.shard_map(..., axis_names=manual,
-    check_vma=...)``; older versions spell it
-    ``jax.experimental.shard_map.shard_map(..., auto=complement,
-    check_rep=...)``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check, axis_names=set(manual_axes))
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check, auto=auto)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

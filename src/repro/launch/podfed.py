@@ -107,13 +107,12 @@ def make_podfed_round_step(cfg: ModelConfig, mesh: Mesh, *,
     batch_in_specs = jax.tree_util.tree_map(
         lambda s: P("pod"), bspecs_tmpl)
 
-    from repro.launch.mesh import shard_map_compat
-    round_fn = shard_map_compat(
-        round_body, mesh,
+    round_fn = jax.shard_map(
+        round_body, mesh=mesh,
         in_specs=(in_state_specs, batch_in_specs),
         out_specs=({k: in_state_specs[k] for k in
                     ("params", "anchor", "g_t")}, {"loss": P()}),
-        manual_axes=("pod",), check=False,
+        axis_names={"pod"}, check_vma=False,
     )
     info = {"num_pods": num_pods, "state_pspecs": in_state_specs,
             "batch_pspec": batch_in_specs}

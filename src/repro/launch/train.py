@@ -24,6 +24,7 @@ from repro.configs.base import FederatedConfig
 from repro.core import FederatedTrainer
 from repro.data.leaf_like import generate_shakespeare_like
 from repro.data.batching import FederatedData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, model_specs, param_count
 from repro.models import transformer
 
@@ -65,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full_size:

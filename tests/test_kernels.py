@@ -112,8 +112,9 @@ def _rand_2d(rows, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("rows,block_rows", [
-    (7, 4),      # prime row count: requested block halves 4 -> 2 -> 1
-    (6, 4),      # non-divisor: halves once to 2
+    (7, 4),      # unaligned rows above the target: one whole-dim block
+    (6, 4),      # same, even row count
+    (40, 16),    # aligned: 8-row blocks (16 does not divide 40)
     (12, None),  # rows < DEFAULT_BLOCK_ROWS: block clamps to rows
 ])
 def test_dane_update_2d_block_degradation(rows, block_rows):
@@ -124,6 +125,25 @@ def test_dane_update_2d_block_degradation(rows, block_rows):
     ref = dane_update_ref(w, g, c, a, eta=0.05, mu=0.3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     assert rows < DEFAULT_BLOCK_ROWS  # the clamp branch is what ran
+
+
+@pytest.mark.parametrize("rows,target,want", [
+    (64, 512, 64),       # fits: the whole dim
+    (640, 512, 320),     # FEMNIST logreg, K=10 flat pack
+    (616, 256, 88),      # largest aligned divisor, not 154
+    (200, 128, 40),      # batch rows of the fused step kernel
+    (620, 512, 620),     # no aligned divisor: the whole dim
+    (130, 128, 130),
+])
+def test_row_block_is_mosaic_aligned(rows, target, want):
+    """Blocks are a multiple of 8 rows that divides ``rows``, or the
+    whole dim — the two shapes Mosaic accepts on the second-minor
+    axis (an unaligned divisor such as 154 of 616 is refused)."""
+    from repro.kernels.dane_update import SUBLANES, row_block
+    bb = row_block(rows, target)
+    assert bb == want
+    assert bb == rows or (bb % SUBLANES == 0 and rows % bb == 0
+                          and bb <= target)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float16, jnp.bfloat16])
@@ -143,13 +163,15 @@ def test_dane_update_2d_low_precision(dtype):
 
 def test_pad_2d_exact_multiple_and_remainder():
     from repro.kernels.ops import _pad_2d
-    a = jnp.arange(256.0)                    # exactly 2 rows of lanes
+    a = jnp.arange(2048.0)                   # exactly 16 rows of lanes
     v, n = _pad_2d(a)
-    assert v.shape == (2, 128) and n == 256
+    assert v.shape == (16, 128) and n == 2048
     np.testing.assert_array_equal(np.asarray(v).ravel(), np.asarray(a))
-    b = jnp.arange(130.0)                    # 2 rows, 126 pad zeros
+    b = jnp.arange(130.0)                    # 2 used rows, padded to 8
     v, n = _pad_2d(b)
-    assert v.shape == (2, 128) and n == 130
+    assert v.shape == (8, 128) and n == 130
+    np.testing.assert_array_equal(np.asarray(v).ravel()[:130],
+                                  np.asarray(b))
     np.testing.assert_array_equal(np.asarray(v).ravel()[130:], 0.0)
 
 

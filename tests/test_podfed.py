@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.launch.mesh import use_mesh
 from repro.launch.podfed import make_podfed_round_step
 from repro.models import init_params, model_specs
 from repro.models import transformer
@@ -37,7 +36,7 @@ def _batch(key, steps=2, b=2, s=16, vocab=128):
 
 def test_podfed_round_finite_and_decreasing(setup):
     mesh, cfg, params = setup
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, _ = make_podfed_round_step(cfg, mesh, local_steps=2,
                                        eta=5e-2, remat="none")
         st = _state(params)
@@ -56,7 +55,7 @@ def test_podfed_matches_single_client_feddane(setup):
     from repro.launch import steps as S
     mesh, cfg, params = setup
     key = jax.random.PRNGKey(2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, _ = make_podfed_round_step(cfg, mesh, local_steps=1,
                                        eta=1e-2, mu=0.01, remat="none")
         st = _state(params)

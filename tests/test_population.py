@@ -257,10 +257,13 @@ def test_sparse_store_matches_dense_carry(case):
 
 
 @settings(max_examples=15, deadline=None)
-@given(st.lists(st.floats(-2.0, 2.0), min_size=1, max_size=8))
+@given(st.lists(st.floats(-2.0, 2.0, width=32, allow_subnormal=False),
+                min_size=1, max_size=8))
 def test_sparse_store_from_dense_roundtrip(vals):
     """from_dense(to_dense(.)) is the identity, and zero rows are not
-    stored (they ARE the shared template)."""
+    stored (they ARE the shared template).  Values are normal float32:
+    XLA flushes a float32 subnormal to zero, so such a row would be a
+    zero row the store rightly drops."""
     rows = [_fill(v) for v in vals]
     sp = SparseClientState.from_dense(rows)
     for a, b in zip(sp.to_dense(), rows):
