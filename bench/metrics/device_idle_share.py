@@ -1,0 +1,9 @@
+"""``device_idle_share`` (%): the share of the traced window in which no
+operation ran on the device, ``1 - busy / window``; busy is the union of
+the device's operation intervals, averaged over the chips."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s() / ctx.trace.window_s)
