@@ -374,112 +374,117 @@ class RoundEngine:
                        active, work, active_a):
             g_global = g_local = None
             grad_ok = avail_n = None
-            if spec.grad_source == "fresh":
-                if with_env:
-                    # offline devices serve no gradient either: g_t is
-                    # the masked mean over the AVAILABLE gather
-                    # selection; with none available there is no
-                    # correction to broadcast (grad_ok zeros it below)
-                    zeros = pt.zeros_like(w0)
-                    avail_n = active_a.sum()
-                    if axis is not None:
-                        avail_n = sharding.tree_psum(avail_n, axis)
-                    grad_ok = (avail_n > 0).astype(jnp.float32)
-                if phase_a is None:
-                    # shared selection: one gradient pass serves the
-                    # gather AND the per-device corrections
-                    g_local = self._grads(w0, batches, valid)
-                    g_global = (server.aggregate_stacked_masked(
-                        g_local, active_a, zeros, axis) if with_env
-                        else server.aggregate_stacked(g_local, axis))
-                else:
-                    ga = self._grads(w0, phase_a[0], phase_a[1])
-                    g_global = (server.aggregate_stacked_masked(
-                        ga, active_a, zeros, axis) if with_env
-                        else server.aggregate_stacked(ga, axis))
-                    if spec.local_grad:
+            with jax.named_scope("phase_a"):
+                if spec.grad_source == "fresh":
+                    if with_env:
+                        # offline devices serve no gradient either: g_t is
+                        # the masked mean over the AVAILABLE gather
+                        # selection; with none available there is no
+                        # correction to broadcast (grad_ok zeros it below)
+                        zeros = pt.zeros_like(w0)
+                        avail_n = active_a.sum()
+                        if axis is not None:
+                            avail_n = sharding.tree_psum(avail_n, axis)
+                        grad_ok = (avail_n > 0).astype(jnp.float32)
+                    if phase_a is None:
+                        # shared selection: one gradient pass serves the
+                        # gather AND the per-device corrections
                         g_local = self._grads(w0, batches, valid)
-            elif spec.grad_source == "stale":
-                g_global = aux["g_prev"]
-                g_local = self._grads(w0, batches, valid)
+                        g_global = (server.aggregate_stacked_masked(
+                            g_local, active_a, zeros, axis) if with_env
+                            else server.aggregate_stacked(g_local, axis))
+                    else:
+                        ga = self._grads(w0, phase_a[0], phase_a[1])
+                        g_global = (server.aggregate_stacked_masked(
+                            ga, active_a, zeros, axis) if with_env
+                            else server.aggregate_stacked(ga, axis))
+                        if spec.local_grad:
+                            g_local = self._grads(w0, batches, valid)
+                elif spec.grad_source == "stale":
+                    g_global = aux["g_prev"]
+                    g_local = self._grads(w0, batches, valid)
 
-            if spec.correction is not None:
-                corr = spec.correction(CorrCtx(
-                    w0=w0, g_global=g_global, g_local=g_local,
-                    c_server=aux.get("c_server"),
-                    c_local=aux.get("controls"),
-                    center=aux.get("center"), mu=mu, decay=decay))
-                if grad_ok is not None:
-                    # no reachable gradient device -> no broadcast ->
-                    # the round runs uncorrected (fedavg/fedprox step)
-                    corr = jax.tree_util.tree_map(
-                        lambda c: c * grad_ok, corr)
-            else:
-                corr = _stack_zeros(w0, valid.shape[0])
-            nsteps = cfg.local_epochs * valid.sum(axis=1)       # (K,)
-            if with_env:
-                # devices stop after ceil(work * total) of their valid
-                # steps — the mask keeps shapes trace-static
-                nsteps = jnp.minimum(jnp.ceil(work * nsteps), nsteps)
-                res = self._solver_env(w0, corr, mu, batches, valid,
-                                       nsteps)
-            else:
-                res = self._solver(w0, corr, mu, batches, valid)
-            new = dict(aux)
-            if codec_trivial:
-                w_agg = (server.aggregate_stacked_masked(
-                    res.params, active, w0, axis) if with_env
-                    else server.aggregate_stacked(res.params, axis))
-            else:
-                w_agg = codec_agg(w0, res.params, aux, new,
-                                  active if with_env else None)
-            if spec.updates_g_prev:
-                new["g_prev"] = (
-                    server.aggregate_stacked_masked(
-                        g_local, active, aux["g_prev"], axis)
-                    if with_env
-                    else server.aggregate_stacked(g_local, axis))
-            if spec.control_update is not None:
-                c_new = spec.control_update(ControlCtx(
-                    c_local=aux["controls"], c_server=aux["c_server"],
-                    w0=w0, w_new=res.params,
-                    inv_steps=1.0 / (jnp.maximum(nsteps, 1.0)
-                                     * cfg.learning_rate)))
-                if with_env:
-                    # only devices whose update reached the server
-                    # refresh their control / feed the server control
-                    keep = lambda cn, co: jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(
-                            active.reshape(active.shape
-                                           + (1,) * (n.ndim - 1)) > 0,
-                            n, o), cn, co)
-                    c_new = keep(c_new, aux["controls"])
-                    delta_sum = jax.tree_util.tree_map(
-                        lambda n, o: (n - o).sum(axis=0),
-                        c_new, aux["controls"])
-                    if axis is not None:
-                        delta_sum = jax.tree_util.tree_map(
-                            lambda d: sharding.tree_psum(d, axis),
-                            delta_sum)
-                    new["c_server"] = jax.tree_util.tree_map(
-                        lambda cs, d: cs + d / n_dev,
-                        aux["c_server"], delta_sum)
+            with jax.named_scope("correction"):
+                if spec.correction is not None:
+                    corr = spec.correction(CorrCtx(
+                        w0=w0, g_global=g_global, g_local=g_local,
+                        c_server=aux.get("c_server"),
+                        c_local=aux.get("controls"),
+                        center=aux.get("center"), mu=mu, decay=decay))
+                    if grad_ok is not None:
+                        # no reachable gradient device -> no broadcast ->
+                        # the round runs uncorrected (fedavg/fedprox step)
+                        corr = jax.tree_util.tree_map(
+                            lambda c: c * grad_ok, corr)
                 else:
-                    delta = server.aggregate_stacked(
-                        pt.sub(c_new, aux["controls"]),
-                        axis)                             # (1/K) sum_k
-                    k = jnp.float32(valid.shape[0] * shards)
-                    new["c_server"] = jax.tree_util.tree_map(
-                        lambda cs, d: cs + d * (k / n_dev),
-                        aux["c_server"], delta)
-                new["controls"] = c_new
-            w_out, opt_state = server.server_step(
-                w0, w_agg, opt, aux.get("opt"))
-            if opt is not None:
-                new["opt"] = opt_state
-            if spec.center_update is not None:
-                new["center"] = spec.center_update(
-                    aux["center"], w_out, cfg)
+                    corr = _stack_zeros(w0, valid.shape[0])
+            with jax.named_scope("local_solve"):
+                nsteps = cfg.local_epochs * valid.sum(axis=1)       # (K,)
+                if with_env:
+                    # devices stop after ceil(work * total) of their valid
+                    # steps — the mask keeps shapes trace-static
+                    nsteps = jnp.minimum(jnp.ceil(work * nsteps), nsteps)
+                    res = self._solver_env(w0, corr, mu, batches, valid,
+                                           nsteps)
+                else:
+                    res = self._solver(w0, corr, mu, batches, valid)
+            with jax.named_scope("aggregate"):
+                new = dict(aux)
+                if codec_trivial:
+                    w_agg = (server.aggregate_stacked_masked(
+                        res.params, active, w0, axis) if with_env
+                        else server.aggregate_stacked(res.params, axis))
+                else:
+                    w_agg = codec_agg(w0, res.params, aux, new,
+                                      active if with_env else None)
+                if spec.updates_g_prev:
+                    new["g_prev"] = (
+                        server.aggregate_stacked_masked(
+                            g_local, active, aux["g_prev"], axis)
+                        if with_env
+                        else server.aggregate_stacked(g_local, axis))
+                if spec.control_update is not None:
+                    c_new = spec.control_update(ControlCtx(
+                        c_local=aux["controls"], c_server=aux["c_server"],
+                        w0=w0, w_new=res.params,
+                        inv_steps=1.0 / (jnp.maximum(nsteps, 1.0)
+                                         * cfg.learning_rate)))
+                    if with_env:
+                        # only devices whose update reached the server
+                        # refresh their control / feed the server control
+                        keep = lambda cn, co: jax.tree_util.tree_map(
+                            lambda n, o: jnp.where(
+                                active.reshape(active.shape
+                                               + (1,) * (n.ndim - 1)) > 0,
+                                n, o), cn, co)
+                        c_new = keep(c_new, aux["controls"])
+                        delta_sum = jax.tree_util.tree_map(
+                            lambda n, o: (n - o).sum(axis=0),
+                            c_new, aux["controls"])
+                        if axis is not None:
+                            delta_sum = jax.tree_util.tree_map(
+                                lambda d: sharding.tree_psum(d, axis),
+                                delta_sum)
+                        new["c_server"] = jax.tree_util.tree_map(
+                            lambda cs, d: cs + d / n_dev,
+                            aux["c_server"], delta_sum)
+                    else:
+                        delta = server.aggregate_stacked(
+                            pt.sub(c_new, aux["controls"]),
+                            axis)                             # (1/K) sum_k
+                        k = jnp.float32(valid.shape[0] * shards)
+                        new["c_server"] = jax.tree_util.tree_map(
+                            lambda cs, d: cs + d * (k / n_dev),
+                            aux["c_server"], delta)
+                    new["controls"] = c_new
+            with jax.named_scope("server_step"):
+                w_out, opt_state = server.server_step(
+                    w0, w_agg, opt, aux.get("opt"))
+                if opt is not None:
+                    new["opt"] = opt_state
+                if spec.center_update is not None:
+                    new["center"] = spec.center_update(
+                        aux["center"], w_out, cfg)
             if with_env:
                 k = jnp.float32(valid.shape[0] * shards)
                 eff = active.sum()
@@ -786,23 +791,24 @@ class ScannedDriver:
             decay = (spec.decay(cfg, xs["t"].astype(jnp.float32))
                      if spec.decay is not None else 1.0)
             full = spec.num_selections == 0
-            if full:
-                b, v = batches_all, valid_all
-                phase_a = None
-            else:
-                b, v = gather(sel_solve)
-                phase_a = (gather(s1)
-                           if (spec.grad_source == "fresh"
-                               and spec.num_selections == 2) else None)
-            aux = {f: carry[f] for f in aux_fields}
-            if has_controls:
-                # full participation touches every control: pass the
-                # carried (N, ...) stack straight through, no
-                # gather/scatter copies on the hot path
-                aux["c_server"] = carry["c_server"]
-                aux["controls"] = (carry["controls"] if full else
-                                   tmap(lambda x: x[sel_solve],
-                                        carry["controls"]))
+            with jax.named_scope("gather"):
+                if full:
+                    b, v = batches_all, valid_all
+                    phase_a = None
+                else:
+                    b, v = gather(sel_solve)
+                    phase_a = (gather(s1)
+                               if (spec.grad_source == "fresh"
+                                   and spec.num_selections == 2) else None)
+                aux = {f: carry[f] for f in aux_fields}
+                if has_controls:
+                    # full participation touches every control: pass the
+                    # carried (N, ...) stack straight through, no
+                    # gather/scatter copies on the hot path
+                    aux["c_server"] = carry["c_server"]
+                    aux["controls"] = (carry["controls"] if full else
+                                       tmap(lambda x: x[sel_solve],
+                                            carry["controls"]))
             if not codec_trivial:
                 # same per-round key as the host loop (domain-separated
                 # fold of the round index), so lossy codec paths agree
@@ -856,10 +862,11 @@ class ScannedDriver:
                              carry["ef"].at[sel_solve].set(
                                  aux_new["ef"]))
             new["params"] = params
-            loss = jax.lax.cond(
-                xs["do_eval"],
-                lambda p: self._eval_loss(p, data["eval"]),
-                lambda p: jnp.float32(jnp.nan), params)
+            with jax.named_scope("eval"):
+                loss = jax.lax.cond(
+                    xs["do_eval"],
+                    lambda p: self._eval_loss(p, data["eval"]),
+                    lambda p: jnp.float32(jnp.nan), params)
             if trivial:
                 return new, loss
             return new, {"loss": loss,
@@ -926,10 +933,11 @@ class ScannedDriver:
             if not codec_trivial and codec.error_feedback:
                 ys["ef"] = aux_new["ef"]
             new["params"] = params
-            ys["loss"] = jax.lax.cond(
-                xs["do_eval"],
-                lambda p: self._eval_loss(p, data["eval"]),
-                lambda p: jnp.float32(jnp.nan), params)
+            with jax.named_scope("eval"):
+                ys["loss"] = jax.lax.cond(
+                    xs["do_eval"],
+                    lambda p: self._eval_loss(p, data["eval"]),
+                    lambda p: jnp.float32(jnp.nan), params)
             if not trivial:
                 ys["effective_k"] = stats["effective_k"]
                 ys["effective_a"] = stats["effective_a"]
@@ -1032,84 +1040,89 @@ class ScannedDriver:
             # chunk at the first within-chunk cohort repeat so xs state
             # rows are never stale; the repeated round restarts the
             # next chunk from its saved key, losing no draws.
-            rows: List[Dict[str, Any]] = []
-            seen: set = set()
-            while off + len(rows) < min(off + chunk_rounds, num_rounds):
-                t = off + len(rows)
-                key_next, row = self._stream_round(
-                    key, t, None if sel is None else sel[t])
-                ids = [int(i) for i in row["sel_solve"]]
-                if stateful and rows and not seen.isdisjoint(ids):
-                    break
-                seen.update(ids)
-                rows.append(row)
-                key = key_next
+            with jax.profiler.TraceAnnotation("stream.schedule"):
+                rows: List[Dict[str, Any]] = []
+                seen: set = set()
+                while off + len(rows) < min(off + chunk_rounds, num_rounds):
+                    t = off + len(rows)
+                    key_next, row = self._stream_round(
+                        key, t, None if sel is None else sel[t])
+                    ids = [int(i) for i in row["sel_solve"]]
+                    if stateful and rows and not seen.isdisjoint(ids):
+                        break
+                    seen.update(ids)
+                    rows.append(row)
+                    key = key_next
             hi = off + len(rows)
             # materialize ONLY the chunk's cohorts, padded to one
             # chunk-wide bucketed batch count (padding rides valid=0
             # masked identity steps — trajectories are exactly the
             # stacked gather's)
-            stacks = [stack_device_batches(self.dataset, r["sel_solve"])
-                      for r in rows]
-            stacks_a = ([stack_device_batches(self.dataset, r["s1"])
-                         for r in rows] if phase2 else None)
-            nb = max(int(s[1].shape[1]) for s in stacks)
-            if stacks_a is not None:
-                nb = max(nb, max(int(s[1].shape[1]) for s in stacks_a))
-            padded = [_pad_cohort(b, v, nb) for b, v in stacks]
-            xs: Dict[str, Any] = {
-                "t": jnp.asarray([r["t"] for r in rows], jnp.int32),
-                "do_eval": jnp.asarray(eval_mask[off:hi]),
-                "b": tmap(lambda *x: jnp.stack(x),
-                          *[p[0] for p in padded]),
-                "v": jnp.stack([p[1] for p in padded])}
-            if stacks_a is not None:
-                padded_a = [_pad_cohort(b, v, nb) for b, v in stacks_a]
-                xs["ba"] = tmap(lambda *x: jnp.stack(x),
-                                *[p[0] for p in padded_a])
-                xs["va"] = jnp.stack([p[1] for p in padded_a])
-            if controls_store is not None:
-                xs["controls"] = tmap(
-                    lambda *x: jnp.stack(x),
-                    *[controls_store.gather(r["sel_solve"])
-                      for r in rows])
-            if ef_store is not None:
-                xs["ef"] = jnp.stack(
-                    [ef_store.gather(r["sel_solve"]) for r in rows])
-            if not self.scn_trivial:
-                xs["active"] = jnp.stack(
-                    [jnp.asarray(r["active"]) for r in rows])
-                xs["work"] = jnp.stack(
-                    [jnp.asarray(r["work"]) for r in rows])
-                if spec.grad_source == "fresh":
-                    xs["active_a"] = jnp.stack(
-                        [jnp.asarray(r["active_a"]) for r in rows])
-            carry, ys = self._chunk_stream(carry, xs, self._data)
-            ys_h = jax.device_get(ys)
-            # scatter updated state rows back, in round order (later
-            # rounds of the chunk never touch earlier rounds' clients —
-            # the truncation above guarantees it)
-            for i, r in enumerate(rows):
+            with jax.profiler.TraceAnnotation("stream.cohorts"):
+                stacks = [stack_device_batches(self.dataset, r["sel_solve"])
+                          for r in rows]
+                stacks_a = ([stack_device_batches(self.dataset, r["s1"])
+                             for r in rows] if phase2 else None)
+            with jax.profiler.TraceAnnotation("stream.pad"):
+                nb = max(int(s[1].shape[1]) for s in stacks)
+                if stacks_a is not None:
+                    nb = max(nb, max(int(s[1].shape[1]) for s in stacks_a))
+                padded = [_pad_cohort(b, v, nb) for b, v in stacks]
+                xs: Dict[str, Any] = {
+                    "t": jnp.asarray([r["t"] for r in rows], jnp.int32),
+                    "do_eval": jnp.asarray(eval_mask[off:hi]),
+                    "b": tmap(lambda *x: jnp.stack(x),
+                              *[p[0] for p in padded]),
+                    "v": jnp.stack([p[1] for p in padded])}
+                if stacks_a is not None:
+                    padded_a = [_pad_cohort(b, v, nb) for b, v in stacks_a]
+                    xs["ba"] = tmap(lambda *x: jnp.stack(x),
+                                    *[p[0] for p in padded_a])
+                    xs["va"] = jnp.stack([p[1] for p in padded_a])
                 if controls_store is not None:
-                    controls_store.scatter(
-                        r["sel_solve"],
-                        tmap(lambda x, i=i: x[i], ys_h["controls"]))
+                    xs["controls"] = tmap(
+                        lambda *x: jnp.stack(x),
+                        *[controls_store.gather(r["sel_solve"])
+                          for r in rows])
                 if ef_store is not None:
-                    ef_store.scatter(r["sel_solve"], ys_h["ef"][i])
-            losses = np.asarray(ys_h["loss"])
-            if self.scn_trivial:
-                eff = np.full(hi - off, intended, dtype=np.float64)
-                eff_a = np.full(hi - off, gather_full, dtype=np.float64)
-            else:
-                eff = np.asarray(ys_h["effective_k"], dtype=np.float64)
-                eff_a = np.asarray(ys_h["effective_a"], dtype=np.float64)
-            self._emit_rounds(hist, off, hi, losses, eff, eff_a,
-                              eval_mask, n_elems, verbose)
-            if checkpoint_dir is not None:
-                from repro.checkpoint.store import save_checkpoint
-                save_checkpoint(checkpoint_dir,
-                                {"params": carry["params"], "round": hi},
-                                step=hi)
+                    xs["ef"] = jnp.stack(
+                        [ef_store.gather(r["sel_solve"]) for r in rows])
+                if not self.scn_trivial:
+                    xs["active"] = jnp.stack(
+                        [jnp.asarray(r["active"]) for r in rows])
+                    xs["work"] = jnp.stack(
+                        [jnp.asarray(r["work"]) for r in rows])
+                    if spec.grad_source == "fresh":
+                        xs["active_a"] = jnp.stack(
+                            [jnp.asarray(r["active_a"]) for r in rows])
+            with jax.profiler.TraceAnnotation("stream.dispatch"):
+                carry, ys = self._chunk_stream(carry, xs, self._data)
+            with jax.profiler.TraceAnnotation("stream.readback"):
+                ys_h = jax.device_get(ys)
+                # scatter updated state rows back, in round order (later
+                # rounds of the chunk never touch earlier rounds' clients —
+                # the truncation above guarantees it)
+                for i, r in enumerate(rows):
+                    if controls_store is not None:
+                        controls_store.scatter(
+                            r["sel_solve"],
+                            tmap(lambda x, i=i: x[i], ys_h["controls"]))
+                    if ef_store is not None:
+                        ef_store.scatter(r["sel_solve"], ys_h["ef"][i])
+                losses = np.asarray(ys_h["loss"])
+                if self.scn_trivial:
+                    eff = np.full(hi - off, intended, dtype=np.float64)
+                    eff_a = np.full(hi - off, gather_full, dtype=np.float64)
+                else:
+                    eff = np.asarray(ys_h["effective_k"], dtype=np.float64)
+                    eff_a = np.asarray(ys_h["effective_a"], dtype=np.float64)
+                self._emit_rounds(hist, off, hi, losses, eff, eff_a,
+                                  eval_mask, n_elems, verbose)
+                if checkpoint_dir is not None:
+                    from repro.checkpoint.store import save_checkpoint
+                    save_checkpoint(checkpoint_dir,
+                                    {"params": carry["params"], "round": hi},
+                                    step=hi)
             off = hi
         return hist, carry["params"]
 
@@ -1215,28 +1228,30 @@ class ScannedDriver:
         carry = self._init_carry(params)
         for off in range(0, num_rounds, chunk_rounds):
             hi = min(off + chunk_rounds, num_rounds)
-            xs = {"t": jnp.asarray(t_all[off:hi], jnp.int32),
-                  "do_eval": jnp.asarray(eval_mask[off:hi])}
-            if sel is not None:
-                xs["sel"] = sel[off:hi]
-            carry, ys = chunk_fn(carry, xs, self._data)
-            # chunk boundary: the only host round-trip
-            if self.scn_trivial:
-                losses = np.asarray(jax.device_get(ys))
-                eff = np.full(hi - off, intended, dtype=np.float64)
-                eff_a = np.full(hi - off, gather_full, dtype=np.float64)
-            else:
-                ys = jax.device_get(ys)
-                losses = np.asarray(ys["loss"])
-                eff = np.asarray(ys["effective_k"], dtype=np.float64)
-                eff_a = np.asarray(ys["effective_a"], dtype=np.float64)
-            self._emit_rounds(hist, off, hi, losses, eff, eff_a,
-                              eval_mask, n_elems, verbose)
-            if checkpoint_dir is not None:
-                from repro.checkpoint.store import save_checkpoint
-                save_checkpoint(checkpoint_dir,
-                                {"params": carry["params"], "round": hi},
-                                step=hi)
+            with jax.profiler.TraceAnnotation("scan.dispatch"):
+                xs = {"t": jnp.asarray(t_all[off:hi], jnp.int32),
+                      "do_eval": jnp.asarray(eval_mask[off:hi])}
+                if sel is not None:
+                    xs["sel"] = sel[off:hi]
+                carry, ys = chunk_fn(carry, xs, self._data)
+            with jax.profiler.TraceAnnotation("scan.readback"):
+                # chunk boundary: the only host round-trip
+                if self.scn_trivial:
+                    losses = np.asarray(jax.device_get(ys))
+                    eff = np.full(hi - off, intended, dtype=np.float64)
+                    eff_a = np.full(hi - off, gather_full, dtype=np.float64)
+                else:
+                    ys = jax.device_get(ys)
+                    losses = np.asarray(ys["loss"])
+                    eff = np.asarray(ys["effective_k"], dtype=np.float64)
+                    eff_a = np.asarray(ys["effective_a"], dtype=np.float64)
+                self._emit_rounds(hist, off, hi, losses, eff, eff_a,
+                                  eval_mask, n_elems, verbose)
+                if checkpoint_dir is not None:
+                    from repro.checkpoint.store import save_checkpoint
+                    save_checkpoint(checkpoint_dir,
+                                    {"params": carry["params"], "round": hi},
+                                    step=hi)
         return hist, carry["params"]
 
 

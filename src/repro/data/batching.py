@@ -76,15 +76,17 @@ def stack_device_batches(dataset, indices) -> Tuple[dict, jnp.ndarray]:
     """
     getter = getattr(dataset, "device_batches_padded", None)
     devs = [dataset.device_batches(int(k)) for k in indices]
-    nbs = [num_batches_of(d) for d in devs]
-    nb_max = max(nbs)
-    if getter is not None:
-        padded = [getter(int(k), nb_max) for k in indices]
-    else:
-        padded = [pad_batch_stack(d, nb_max) for d in devs]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
-    valid = jnp.asarray(
-        np.arange(nb_max)[None, :] < np.asarray(nbs)[:, None], jnp.float32)
+    with jax.profiler.TraceAnnotation("cohort.pad"):
+        nbs = [num_batches_of(d) for d in devs]
+        nb_max = max(nbs)
+        if getter is not None:
+            padded = [getter(int(k), nb_max) for k in indices]
+        else:
+            padded = [pad_batch_stack(d, nb_max) for d in devs]
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+        valid = jnp.asarray(
+            np.arange(nb_max)[None, :] < np.asarray(nbs)[:, None],
+            jnp.float32)
     return stacked, valid
 
 

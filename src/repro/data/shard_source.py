@@ -44,6 +44,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, Iterable, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.data.batching import (FederatedData, pad_batch_stack,
@@ -74,7 +75,6 @@ def resolve_streaming(client_source: str, dataset) -> bool:
 
 
 def _tree_bytes(batches) -> int:
-    import jax
     return sum(int(np.prod(x.shape)) * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(batches))
 
@@ -134,23 +134,25 @@ class ClientShardSource:
     def device_batches(self, k: int):
         """Client k's padded ``(num_batches, batch, ...)`` stack,
         generated on first touch and LRU-cached."""
-        k = int(k)
-        hit = self._cache.get(k)
-        if hit is not None:
-            self._cache.move_to_end(k)
-            return hit
-        self.materialized_clients += 1
-        arrays = self._client_arrays(k)
-        self._sizes[k] = next(iter(arrays.values())).shape[0]
-        batches = pad_to_batches(arrays, self.batch_size)
-        self._cache[k] = batches
-        self.cache_bytes += _tree_bytes(batches)
-        while len(self._cache) > self.cache_clients:
-            _, old = self._cache.popitem(last=False)
-            self.cache_bytes -= _tree_bytes(old)
-        self.peak_cache_bytes = max(self.peak_cache_bytes,
-                                    self.cache_bytes)
-        return batches
+        with jax.profiler.TraceAnnotation("cohort.fetch"):
+            k = int(k)
+            hit = self._cache.get(k)
+            if hit is not None:
+                self._cache.move_to_end(k)
+                return hit
+            self.materialized_clients += 1
+            with jax.profiler.TraceAnnotation("cohort.make"):
+                arrays = self._client_arrays(k)
+            self._sizes[k] = next(iter(arrays.values())).shape[0]
+            batches = pad_to_batches(arrays, self.batch_size)
+            self._cache[k] = batches
+            self.cache_bytes += _tree_bytes(batches)
+            while len(self._cache) > self.cache_clients:
+                _, old = self._cache.popitem(last=False)
+                self.cache_bytes -= _tree_bytes(old)
+            self.peak_cache_bytes = max(self.peak_cache_bytes,
+                                        self.cache_bytes)
+            return batches
 
     def device_batches_padded(self, k: int, nb: int):
         """``stack_device_batches``'s padding hook: cycle client k's
