@@ -135,9 +135,10 @@ Population-scale streaming (``ClientShardSource``)
   uniforms are bit-identical to the stacked scan; it then materializes
   ONLY the selected cohorts' batches from the
   :class:`~repro.data.shard_source.ClientShardSource` and feeds them
-  through the scan's ``xs`` (padded to a chunk-wide bucketed batch
-  count — padding rides ``valid=0`` masked identity steps, so
-  trajectories match the stacked gather exactly).  Per-client
+  through the scan's ``xs``, padded on the host to a chunk-wide
+  bucketed batch count (padding rides ``valid=0`` masked identity
+  steps, so trajectories match the stacked gather exactly) and moved
+  to the device in one transfer per leaf a chunk.  Per-client
   persistent state (SCAFFOLD controls, codec error feedback) lives in
   host-side :class:`~repro.core.client_state.SparseClientState` stores:
   cohort rows ride ``xs`` in, updated rows ride the scan outputs back,
@@ -166,7 +167,8 @@ from repro.core.scenarios import (availability_mask, env_channels,
 from repro.core.strategies import (AlgorithmSpec, ControlCtx, CorrCtx,
                                    algorithm_spec, init_aux,
                                    make_server_opt, runtime_state_fields)
-from repro.data.batching import stack_device_batches, stack_eval_batches
+from repro.data.batching import (stack_device_batches, stack_eval_batches,
+                                 stack_host_batches)
 from repro.data.shard_source import resolve_streaming
 from repro.kernels.codec import codec_aggregate, codec_aggregate_partial
 from repro.kernels.flatpack import (LANES, flat_spec, pack_broadcast,
@@ -560,21 +562,25 @@ class RoundEngine:
             wrapped(w0, aux, phase_a, batches, valid, decay)
 
 
-def _pad_cohort(stacked, valid, nb: int):
-    """Pad one round's ``(K, nb_r, ...)`` cohort stack to the streaming
-    chunk's shared bucketed batch count ``nb``: batch steps cycle (the
-    extra steps ride ``valid=0`` masked identity updates), the valid
-    mask extends with zeros — so the padded trajectory is exactly the
-    unpadded one and chunk shapes stay uniform for one scan trace."""
-    cur = int(valid.shape[1])
-    if cur == nb:
-        return stacked, valid
-    idx = jnp.arange(nb) % cur
-    stacked = jax.tree_util.tree_map(lambda x: x[:, idx], stacked)
-    valid = jnp.concatenate(
-        [valid, jnp.zeros((valid.shape[0], nb - cur), valid.dtype)],
-        axis=1)
-    return stacked, valid
+def _stack_chunk(stacks, nb: int):
+    """Stack a chunk's host cohorts ``(K, nb_r, ...)`` and masks into
+    one ``(R, K, nb, ...)`` host array per leaf at the chunk's shared
+    bucketed batch count ``nb``: batch steps cycle (the extra steps ride
+    ``valid=0`` masked identity updates), the valid mask extends with
+    zeros — so the padded trajectory is exactly the unpadded one and
+    chunk shapes stay uniform for one scan trace."""
+    def put(*xs):
+        out = np.empty((len(xs), xs[0].shape[0], nb) + xs[0].shape[2:],
+                       xs[0].dtype)
+        for r, x in enumerate(xs):
+            out[r] = x[:, np.arange(nb) % x.shape[1]]
+        return out
+
+    b = jax.tree_util.tree_map(put, *[s[0] for s in stacks])
+    v = np.zeros((len(stacks), stacks[0][1].shape[0], nb), np.float32)
+    for r, (_, vr) in enumerate(stacks):
+        v[r, :, :vr.shape[1]] = vr
+    return b, v
 
 
 def _make_stacked_eval(loss_fn: Callable) -> Callable:
@@ -663,8 +669,8 @@ class ScannedDriver:
         #: materializes selected cohorts only, per chunk, host-side.
         #: Full-participation specs touch every client every round —
         #: inherently materializing — so they run the stacked plan on
-        #: either source kind (a streaming source materializes through
-        #: its device_batches_padded hook; small N only).
+        #: either source kind (a streaming source's host stacks are
+        #: stacked on the host and moved once; small N only).
         self.streaming = (resolve_streaming(
             getattr(cfg, "client_source", "auto"), dataset)
             and self.spec.num_selections > 0)
@@ -994,6 +1000,18 @@ class ScannedDriver:
                     scn, cfg, n, sel_a, t_f, uniforms))
         return key, row
 
+    def _put_xs(self, xs: Dict[str, Any]) -> Dict[str, Any]:
+        """Move a streaming chunk's ``xs`` to the device, one transfer
+        per leaf.  On a mesh the per-round cohort leaves ``(R, K, ...)``
+        shard their client axis as the shard-mapped round body takes it
+        and ``t`` / ``do_eval`` replicate."""
+        if self.mesh is None:
+            return jax.device_put(xs)
+        cohort = sharding.chunk_stacked_sharding(self.mesh)
+        rep = sharding.replicated_sharding(self.mesh)
+        return jax.device_put(xs, {k: rep if k in ("t", "do_eval")
+                                   else cohort for k in xs})
+
     def _init_stream_carry(self, params):
         """The streaming carry: params + the spec's GLOBAL state only.
         Per-client state lives host-side in ``SparseClientState``
@@ -1059,26 +1077,22 @@ class ScannedDriver:
             # masked identity steps — trajectories are exactly the
             # stacked gather's)
             with jax.profiler.TraceAnnotation("stream.cohorts"):
-                stacks = [stack_device_batches(self.dataset, r["sel_solve"])
+                stacks = [stack_host_batches(self.dataset, r["sel_solve"])
                           for r in rows]
-                stacks_a = ([stack_device_batches(self.dataset, r["s1"])
+                stacks_a = ([stack_host_batches(self.dataset, r["s1"])
                              for r in rows] if phase2 else None)
+            # the whole chunk's xs is assembled on the host and moved to
+            # the device in one transfer per leaf
             with jax.profiler.TraceAnnotation("stream.pad"):
                 nb = max(int(s[1].shape[1]) for s in stacks)
                 if stacks_a is not None:
                     nb = max(nb, max(int(s[1].shape[1]) for s in stacks_a))
-                padded = [_pad_cohort(b, v, nb) for b, v in stacks]
                 xs: Dict[str, Any] = {
-                    "t": jnp.asarray([r["t"] for r in rows], jnp.int32),
-                    "do_eval": jnp.asarray(eval_mask[off:hi]),
-                    "b": tmap(lambda *x: jnp.stack(x),
-                              *[p[0] for p in padded]),
-                    "v": jnp.stack([p[1] for p in padded])}
+                    "t": np.asarray([r["t"] for r in rows], np.int32),
+                    "do_eval": eval_mask[off:hi]}
+                xs["b"], xs["v"] = _stack_chunk(stacks, nb)
                 if stacks_a is not None:
-                    padded_a = [_pad_cohort(b, v, nb) for b, v in stacks_a]
-                    xs["ba"] = tmap(lambda *x: jnp.stack(x),
-                                    *[p[0] for p in padded_a])
-                    xs["va"] = jnp.stack([p[1] for p in padded_a])
+                    xs["ba"], xs["va"] = _stack_chunk(stacks_a, nb)
                 if controls_store is not None:
                     xs["controls"] = tmap(
                         lambda *x: jnp.stack(x),
@@ -1088,13 +1102,12 @@ class ScannedDriver:
                     xs["ef"] = jnp.stack(
                         [ef_store.gather(r["sel_solve"]) for r in rows])
                 if not self.scn_trivial:
-                    xs["active"] = jnp.stack(
-                        [jnp.asarray(r["active"]) for r in rows])
-                    xs["work"] = jnp.stack(
-                        [jnp.asarray(r["work"]) for r in rows])
+                    xs["active"] = np.stack([r["active"] for r in rows])
+                    xs["work"] = np.stack([r["work"] for r in rows])
                     if spec.grad_source == "fresh":
-                        xs["active_a"] = jnp.stack(
-                            [jnp.asarray(r["active_a"]) for r in rows])
+                        xs["active_a"] = np.stack(
+                            [r["active_a"] for r in rows])
+                xs = self._put_xs(xs)
             with jax.profiler.TraceAnnotation("stream.dispatch"):
                 carry, ys = self._chunk_stream(carry, xs, self._data)
             with jax.profiler.TraceAnnotation("stream.readback"):

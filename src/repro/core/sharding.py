@@ -222,6 +222,13 @@ def stacked_spec(mesh: Optional[Mesh] = None) -> PartitionSpec:
     return PartitionSpec(DEVICE_AXIS)
 
 
+def chunk_stacked_sharding(mesh: Mesh) -> NamedSharding:
+    """:func:`stacked_spec` one axis in, bound to ``mesh``: the layout
+    of per-round stacks ``(R, K, ...)`` scanned over their leading
+    round axis (the streaming chunk's cohorts and masks)."""
+    return NamedSharding(mesh, PartitionSpec(None, *stacked_spec(mesh)))
+
+
 def replicated_spec() -> PartitionSpec:
     """Fully-replicated layout for global round state (``w0``,
     ``g_prev``, ``c_server``, ``center``, opt state, scalars)."""

@@ -13,6 +13,10 @@ selection and stacked along a new leading device axis, together with a
 ``(K, num_batches)`` validity mask.  Because per-device ``num_batches``
 is already a power of two, the stacked shape is too, so the engine's
 jitted round functions compile O(log max_batches) times.
+
+Streaming sources keep each device's stack on the host
+(``host_batches``); their cohorts are padded and stacked in NumPy
+(``stack_host_batches``) and reach the device in one transfer per leaf.
 """
 from __future__ import annotations
 
@@ -28,8 +32,12 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-def pad_to_batches(arrays: Dict[str, np.ndarray], batch_size: int,
-                   bucket: bool = True) -> Dict[str, jnp.ndarray]:
+def host_batches(arrays: Dict[str, np.ndarray], batch_size: int,
+                 bucket: bool = True) -> Dict[str, np.ndarray]:
+    """One device's ``(num_batches, batch, ...)`` stack as host arrays,
+    in the dtype a device transfer would give (float64 -> float32 with
+    x64 off).  Shared by ``FederatedData`` (which moves it to the
+    device) and the streaming sources (which keep it on the host)."""
     n = next(iter(arrays.values())).shape[0]
     nb = max(1, math.ceil(n / batch_size))
     if bucket:
@@ -38,10 +46,17 @@ def pad_to_batches(arrays: Dict[str, np.ndarray], batch_size: int,
     idx = np.arange(target) % n           # cycle the device's own examples
     out = {}
     for k, a in arrays.items():
-        padded = a[idx]
-        out[k] = jnp.asarray(
-            padded.reshape((nb, batch_size) + a.shape[1:]))
+        padded = a[idx].astype(jax.dtypes.canonicalize_dtype(a.dtype),
+                               copy=False)
+        out[k] = padded.reshape((nb, batch_size) + a.shape[1:])
     return out
+
+
+def pad_to_batches(arrays: Dict[str, np.ndarray], batch_size: int,
+                   bucket: bool = True) -> Dict[str, jnp.ndarray]:
+    """:func:`host_batches` moved to the device."""
+    return {k: jnp.asarray(v)
+            for k, v in host_batches(arrays, batch_size, bucket).items()}
 
 
 def num_batches_of(batches) -> int:
@@ -64,6 +79,38 @@ def pad_batch_stack(batches, nb: int):
     return jax.tree_util.tree_map(lambda x: x[idx], batches)
 
 
+def _stack_cycled(xs, nb: int) -> np.ndarray:
+    """Stack host ``(nb_k, ...)`` arrays into ``(K, nb, ...)``, each
+    cycled out to ``nb`` by the :func:`pad_batch_stack` rule."""
+    out = np.empty((len(xs), nb) + xs[0].shape[1:], xs[0].dtype)
+    for i, x in enumerate(xs):
+        out[i] = x[np.arange(nb) % x.shape[0]]
+    return out
+
+
+def _valid_mask(nbs, nb: int) -> np.ndarray:
+    """``(K, nb)`` float32: 1 on each device's own batches."""
+    return (np.arange(nb)[None, :]
+            < np.asarray(nbs)[:, None]).astype(np.float32)
+
+
+def _stack_host(devs) -> Tuple[dict, np.ndarray]:
+    """Fetched host stacks -> ``(stacked, valid)`` NumPy arrays."""
+    with jax.profiler.TraceAnnotation("cohort.pad"):
+        nbs = [num_batches_of(d) for d in devs]
+        nb_max = max(nbs)
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: _stack_cycled(xs, nb_max), *devs)
+        return stacked, _valid_mask(nbs, nb_max)
+
+
+def stack_host_batches(dataset, indices) -> Tuple[dict, np.ndarray]:
+    """:func:`stack_device_batches` on the host: each selected device's
+    host stack (a streaming source's) is fetched once, cycled out to the
+    selection's largest bucket and stacked into NumPy arrays."""
+    return _stack_host([dataset.device_batches(int(k)) for k in indices])
+
+
 def stack_device_batches(dataset, indices) -> Tuple[dict, jnp.ndarray]:
     """Stack the selected devices' batch stacks along a leading device axis.
 
@@ -73,20 +120,24 @@ def stack_device_batches(dataset, indices) -> Tuple[dict, jnp.ndarray]:
     only exist to reach the common ``nb_max``.  Masked batches must be
     no-ops in the engine (zero gradient weight, identity SGD step), which
     preserves exact numerical parity with the per-device looped path.
+
+    Host stacks (a streaming source's) are padded and stacked on the
+    host and moved to the device once per leaf; device stacks
+    (``FederatedData``'s) are padded where they live.
     """
-    getter = getattr(dataset, "device_batches_padded", None)
     devs = [dataset.device_batches(int(k)) for k in indices]
+    if isinstance(jax.tree_util.tree_leaves(devs[0])[0], np.ndarray):
+        return jax.device_put(_stack_host(devs))
     with jax.profiler.TraceAnnotation("cohort.pad"):
         nbs = [num_batches_of(d) for d in devs]
         nb_max = max(nbs)
+        getter = getattr(dataset, "device_batches_padded", None)
         if getter is not None:
             padded = [getter(int(k), nb_max) for k in indices]
         else:
             padded = [pad_batch_stack(d, nb_max) for d in devs]
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
-        valid = jnp.asarray(
-            np.arange(nb_max)[None, :] < np.asarray(nbs)[:, None],
-            jnp.float32)
+        valid = jnp.asarray(_valid_mask(nbs, nb_max))
     return stacked, valid
 
 
@@ -110,8 +161,7 @@ def stack_eval_batches(dataset) -> Tuple[dict, jnp.ndarray, jnp.ndarray]:
     nb_max = max(nbs)
     padded = [pad_batch_stack(b, nb_max) for b in stacks]
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
-    valid = jnp.asarray(
-        np.arange(nb_max)[None, :] < np.asarray(nbs)[:, None], jnp.float32)
+    valid = jnp.asarray(_valid_mask(nbs, nb_max))
     return stacked, valid, jnp.asarray(weights, jnp.float32)
 
 

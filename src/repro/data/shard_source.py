@@ -8,15 +8,15 @@ the paper actually targets (K=10 of N=1,000,000).
 
 A :class:`ClientShardSource` is the streaming half of the same dataset
 protocol: it exposes ``num_devices`` / ``device_batches(k)`` /
-``device_batches_padded(k, nb)`` / ``eval_batches()`` exactly like
-``FederatedData``, but materializes a client's arrays only when that
-client is actually touched (selected into a round cohort, or part of
-the bounded eval sample).  Per-client data comes from an **O(1)
-seed-per-client** construction — ``np.random.default_rng([seed, tag,
-k])`` — so client k's shard is identical no matter which cohorts it
-appears in, in which order, or on which host.  A bounded LRU cache
-keeps the hot cohort's padded batch stacks; everything else is
-regenerated on demand.
+``eval_batches()`` exactly like ``FederatedData``, but materializes a
+client's arrays only when that client is actually touched (selected
+into a round cohort, or part of the bounded eval sample), and keeps
+them on the host until a whole cohort or chunk moves to the device.
+Per-client data comes from an **O(1) seed-per-client** construction —
+``np.random.default_rng([seed, tag, k])`` — so client k's shard is
+identical no matter which cohorts it appears in, in which order, or on
+which host.  A bounded LRU cache keeps the hot cohort's padded batch
+stacks; everything else is regenerated on demand.
 
 Contract notes
 --------------
@@ -47,8 +47,7 @@ from typing import Dict, Iterable, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.data.batching import (FederatedData, pad_batch_stack,
-                                 pad_to_batches)
+from repro.data.batching import FederatedData, host_batches
 
 #: Seed-sequence domain tags: per-client streams, dataset-shared
 #: structures, and the eval-sample draw must never collide.
@@ -132,8 +131,10 @@ class ClientShardSource:
     # -- the FederatedData protocol -----------------------------------
 
     def device_batches(self, k: int):
-        """Client k's padded ``(num_batches, batch, ...)`` stack,
-        generated on first touch and LRU-cached."""
+        """Client k's padded ``(num_batches, batch, ...)`` stack as host
+        NumPy arrays, generated on first touch and LRU-cached; cohorts
+        are padded and stacked on the host and moved to the device
+        whole (``data.batching.stack_host_batches``)."""
         with jax.profiler.TraceAnnotation("cohort.fetch"):
             k = int(k)
             hit = self._cache.get(k)
@@ -144,7 +145,7 @@ class ClientShardSource:
             with jax.profiler.TraceAnnotation("cohort.make"):
                 arrays = self._client_arrays(k)
             self._sizes[k] = next(iter(arrays.values())).shape[0]
-            batches = pad_to_batches(arrays, self.batch_size)
+            batches = host_batches(arrays, self.batch_size)
             self._cache[k] = batches
             self.cache_bytes += _tree_bytes(batches)
             while len(self._cache) > self.cache_clients:
@@ -153,12 +154,6 @@ class ClientShardSource:
             self.peak_cache_bytes = max(self.peak_cache_bytes,
                                         self.cache_bytes)
             return batches
-
-    def device_batches_padded(self, k: int, nb: int):
-        """``stack_device_batches``'s padding hook: cycle client k's
-        stack out to ``nb`` batches (not cached — cohort paddings are
-        transient and cohort-sized)."""
-        return pad_batch_stack(self.device_batches(k), nb)
 
     def eval_ids(self) -> np.ndarray:
         """The fixed eval-sample client ids (all ids, in order, when

@@ -11,6 +11,9 @@ Checks, all at atol 1e-5 over 3 rounds with injected selections:
   ``mesh_devices=1`` (final params AND loss history);
 - the scanned driver for a two-phase, a control-variate, and a
   full-participation spec;
+- the scanned driver's streaming plan (feddane, ``mesh_devices=4`` vs
+  ``1``, two chunks): each chunk's ``xs`` placed with its client axis
+  sharded over the mesh;
 - one non-ideal scenario (``bernoulli`` availability) under both
   drivers — masked aggregation via psum collectives — including the
   realized ``effective_k`` telemetry;
@@ -48,8 +51,9 @@ import numpy as np
 
 from repro.configs.base import FederatedConfig
 from repro.core import FederatedTrainer, available_algorithms
-from repro.core.sharding import resolve_mesh_devices
-from repro.data import make_synthetic
+from repro.core.engine import make_scanned_run
+from repro.core.sharding import chunk_stacked_sharding, resolve_mesh_devices
+from repro.data import make_synthetic, make_synthetic_stream
 from repro.models.param import init_params
 from repro.models.small import logreg_loss, logreg_specs
 
@@ -100,6 +104,41 @@ def main() -> None:
         dmax = leaves_maxdiff(f1, f8)
         assert dmax < ATOL, f"{algo}: sharded scan diverged ({dmax:.2e})"
         print(f"ok scan {algo}: params {dmax:.2e}")
+
+    # the streaming plan on a mesh: each chunk's xs is assembled on the
+    # host and placed in one transfer per leaf with its client axis
+    # sharded as the shard-mapped round body takes it (two chunks)
+    stream = make_synthetic_stream(1, 1, num_devices=N, seed=0)
+
+    def run_stream(mesh_devices):
+        cfg = FederatedConfig(
+            algorithm="feddane", num_devices=N, devices_per_round=K,
+            local_epochs=2, learning_rate=0.01, mu=0.001, seed=3,
+            engine="batched", round_driver="scan", chunk_rounds=2,
+            client_source="streaming", mesh_devices=mesh_devices)
+        drv = make_scanned_run(logreg_loss, stream, cfg)
+        chunk, placed = drv._chunk_stream, []
+
+        def record(carry, xs, data):
+            placed.append(xs["b"]["x"].sharding)
+            return chunk(carry, xs, data)
+
+        drv._chunk_stream = record
+        hist, final = drv.run(params, ROUNDS, selections=sel)
+        assert drv.streaming and len(placed) == 2, placed
+        return hist, final, placed, drv.mesh
+
+    h1, f1, _, _ = run_stream(1)
+    h4, f4, placed, mesh = run_stream(4)
+    want = chunk_stacked_sharding(mesh)
+    assert all(p.is_equivalent_to(want, 5) for p in placed), placed
+    dmax = leaves_maxdiff(f1, f4)
+    ldiff = float(np.abs(np.asarray(h1["loss"])
+                         - np.asarray(h4["loss"])).max())
+    assert dmax < ATOL and ldiff < ATOL, (
+        f"streaming mesh diverged (params {dmax:.2e}, loss {ldiff:.2e})")
+    assert h4["sharded"] == [1.0] * ROUNDS, h4["sharded"]
+    print(f"ok streaming mesh 4: params {dmax:.2e} loss {ldiff:.2e}")
 
     # mesh_devices="auto" == the explicit full mesh, to the bit
     _, f8 = run("feddane", 8)

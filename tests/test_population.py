@@ -44,7 +44,10 @@ from repro.configs.base import FederatedConfig
 from repro.core import FederatedTrainer
 from repro.core.client_state import SparseClientState
 from repro.data import FederatedData, make_synthetic_stream
-from repro.data.batching import stack_eval_batches
+from repro.data.batching import (num_batches_of, pad_batch_stack,
+                                 stack_device_batches, stack_eval_batches,
+                                 stack_host_batches)
+from repro.data.shard_source import ClientShardSource
 from repro.models.param import init_params
 from repro.models.small import logreg_loss, logreg_specs
 
@@ -184,6 +187,92 @@ def test_source_telemetry_counts_cohorts(setup):
     assert s["materialized_clients"] == N     # each client generated once
     assert s["peak_cache_bytes"] > 0
     assert s["cached_clients"] <= N
+
+
+# -- 1b. the host data plan: cohorts stay NumPy until the chunk moves --
+
+class _SizedSource(ClientShardSource):
+    """Clients of fixed sizes: at batch size 10, 5 / 35 / 160 samples
+    bucket to 1 / 4 / 16 batches."""
+
+    SIZES = (5, 35, 160, 35, 5)
+
+    def __init__(self):
+        super().__init__(len(self.SIZES), batch_size=10, seed=4)
+
+    def _client_arrays(self, k):
+        rng = self.client_rng(k)
+        n = self.SIZES[k]
+        return {"x": rng.normal(size=(n, 3)).astype(np.float32),
+                "y": rng.integers(0, 10, n).astype(np.int32)}
+
+
+def _stream_two_chunks(src, k=K, rounds=4, **kw):
+    """A feddane streaming scan run of two chunks on ``src``."""
+    kw = dict(algorithm="feddane", num_devices=src.num_devices,
+              devices_per_round=k, engine="batched", round_driver="scan",
+              chunk_rounds=rounds // 2, client_source="streaming", **kw)
+    return _run(src, init_params(logreg_specs(60, 10),
+                                 jax.random.PRNGKey(0)), rounds=rounds,
+                **kw)
+
+
+def test_streaming_source_caches_host_arrays():
+    src = make_synthetic_stream(0.5, 0.5, num_devices=200, seed=9)
+    _stream_two_chunks(src)
+    assert src._cache
+    for batches in src._cache.values():
+        for leaf in jax.tree_util.tree_leaves(batches):
+            assert isinstance(leaf, np.ndarray), type(leaf)
+
+
+def test_stack_host_batches_matches_the_device_rule():
+    """Mixed buckets (1, 4, 16) cycle out to the largest exactly as
+    ``pad_batch_stack`` then a stack would, with the same mask."""
+    src = _SizedSource()
+    ids = [0, 1, 2, 3, 4]
+    stacked, valid = stack_host_batches(src, ids)
+    own = [jax.tree_util.tree_map(jnp.asarray, src.device_batches(k))
+           for k in ids]
+    assert [num_batches_of(b) for b in own] == [1, 4, 16, 4, 1]
+    ref = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), *[pad_batch_stack(b, 16) for b in own])
+    for key in ("x", "y"):
+        assert isinstance(stacked[key], np.ndarray)
+        assert stacked[key].dtype == ref[key].dtype
+        np.testing.assert_array_equal(stacked[key], np.asarray(ref[key]))
+    ref_valid = np.arange(16)[None, :] < np.array([1, 4, 16, 4, 1])[:, None]
+    assert valid.dtype == np.float32
+    np.testing.assert_array_equal(valid, ref_valid.astype(np.float32))
+    # the device-facing wrapper returns the same stack, on the device
+    b, v = stack_device_batches(src, ids)
+    assert isinstance(v, jax.Array)
+    np.testing.assert_array_equal(np.asarray(b["x"]), stacked["x"])
+    np.testing.assert_array_equal(np.asarray(v), valid)
+
+
+def test_streaming_chunk_moves_each_cohort_leaf_once(monkeypatch):
+    """Each chunk's ``b`` / ``ba`` leaves reach the device as one
+    ``(R, K, nb, B, ...)`` transfer each: no client's stack is moved on
+    its own."""
+    put = jax.device_put
+    moved = []
+
+    def record(x, *args, **kw):
+        moved.append([np.shape(leaf)
+                      for leaf in jax.tree_util.tree_leaves(x)])
+        return put(x, *args, **kw)
+
+    monkeypatch.setattr(jax, "device_put", record)
+    rounds, chunk = 4, 2
+    _stream_two_chunks(make_synthetic_stream(0.5, 0.5, num_devices=200,
+                                             seed=9), rounds=rounds)
+    features = [[s for s in call if s[-1:] == (60,)] for call in moved]
+    # two chunks, each one call carrying b's and ba's x leaf whole
+    assert [len(f) for f in features if f] == [2, 2]
+    for f in features:
+        for shape in f:
+            assert len(shape) == 5 and shape[:2] == (chunk, K), shape
 
 
 # -- 2. sparse client-state store == dense carry (property tests) ------
