@@ -35,6 +35,10 @@ Contract notes
   sequential stream over clients, which is exactly the O(N) coupling
   streaming removes).  Parity is between *streaming and materialized
   execution over the same streaming data*, not across generators.
+- Synthetic labels are drawn in one vectorised pass
+  (``synthetic.categorical_rows``), bitwise equal to one
+  ``Generator.choice`` per sample; ``bench/datagen/synthetic_stream.py``
+  keeps the per-sample loop and relies on that equality.
 - Telemetry: ``materialized_clients`` (generator invocations; cache
   hits do not count), ``cache_bytes`` / ``peak_cache_bytes`` — what
   the population memory tests and ``population_*`` bench rows assert.
@@ -229,7 +233,7 @@ class SyntheticShardSource(ClientShardSource):
         self._b_shared = shared.normal(0, 1, self._nc)
 
     def _client_arrays(self, k: int) -> Dict[str, np.ndarray]:
-        from repro.data.synthetic import _softmax
+        from repro.data.synthetic import _softmax, categorical_rows
         rng = self.client_rng(k)
         n = int(np.clip(rng.lognormal(4.0, 2.0) + self.min_samples,
                         self.min_samples, 1000))
@@ -245,7 +249,7 @@ class SyntheticShardSource(ClientShardSource):
                        (n, self._nf))
         logits = x @ W + b
         probs = _softmax(logits)
-        y = np.array([rng.choice(self._nc, p=p) for p in probs])
+        y = categorical_rows(rng, probs)
         return {"x": x.astype(np.float32), "y": y.astype(np.int32)}
 
 

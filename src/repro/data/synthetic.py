@@ -27,6 +27,22 @@ def _softmax(z):
     return e / e.sum(axis=-1, keepdims=True)
 
 
+def categorical_rows(rng: np.random.Generator, probs: np.ndarray
+                     ) -> np.ndarray:
+    """For each row of ``probs`` (shape ``(n, C)``), the index that
+    ``rng.choice(C, p=row)`` returns, drawn for all rows at once.
+
+    ``choice`` builds ``cdf = p.cumsum(); cdf /= cdf[-1]``, draws one
+    double ``u`` and returns ``cdf.searchsorted(u, side="right")``, the
+    count of cdf entries ``<= u``.  One ``rng.random(n)`` yields the
+    same n doubles as n scalar draws, so the labels and the generator's
+    state afterwards are bitwise those of the per-row loop."""
+    cdf = np.cumsum(probs, axis=1)
+    cdf /= cdf[:, -1:]
+    u = rng.random(len(probs))
+    return (cdf <= u[:, None]).sum(axis=1)
+
+
 def generate_synthetic(alpha: float, beta: float, *, iid: bool = False,
                        num_devices: int = 30, seed: int = 0,
                        min_samples: int = 50) -> List[Dict[str, np.ndarray]]:
@@ -53,7 +69,7 @@ def generate_synthetic(alpha: float, beta: float, *, iid: bool = False,
         n = int(sizes[k])
         x = rng.normal(mean_x, np.sqrt(cov_diag), (n, NUM_FEATURES))
         probs = _softmax(x @ W + b)
-        y = np.array([rng.choice(NUM_CLASSES, p=p) for p in probs])
+        y = categorical_rows(rng, probs)
         devices.append({"x": x.astype(np.float32),
                         "y": y.astype(np.int32)})
     return devices
